@@ -64,6 +64,7 @@ from repro.dist import collectives as C
 from repro.launch.mesh import force_host_devices, make_mesh, parse_mesh
 from repro.models import ModelConfig, get_model
 from repro.obs import Obs, Tracer
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serve import (
     ChaosConfig,
     ChaosMonkey,
@@ -397,6 +398,7 @@ def main(argv=None):
                          "fraction (0.10 = at most 10%% slower)")
     ap.add_argument("--out", default="BENCH_serving.json")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     n_requests = args.trace_len or (8 if args.fast else 24)
     capacities = [2, 4] if args.fast else [2, 4, 8]

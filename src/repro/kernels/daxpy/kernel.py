@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 
 def _daxpy_kernel(n_ref, a_ref, x_ref, y_ref, o_ref, *, block: int):
     pid = pl.program_id(0)
@@ -28,8 +30,8 @@ def _daxpy_kernel(n_ref, a_ref, x_ref, y_ref, o_ref, *, block: int):
     o_ref[...] = jnp.where(p, fused, y_ref[...])  # /m merging predication
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def daxpy_pallas(x, y, a, n, *, block: int = 1024, interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("block",))
+def daxpy_pallas(x, y, a, n, *, block: int = 1024):
     """x, y: (padded_len,) arrays; a: scalar; n: active element count."""
     padded = x.shape[0]
     assert padded % block == 0, (padded, block)
@@ -46,7 +48,7 @@ def daxpy_pallas(x, y, a, n, *, block: int = 1024, interpret: bool = True):
         ],
         out_specs=pl.BlockSpec((1, block), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, padded), x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(),
     )(
         jnp.asarray([n], jnp.int32),
         jnp.asarray([a], x.dtype),

@@ -9,7 +9,7 @@ from repro.core import vla
 from .kernel import daxpy_pallas
 
 
-def daxpy(x, y, a, n=None, *, block: int | None = None, interpret: bool = True):
+def daxpy(x, y, a, n=None, *, block: int | None = None):
     """Vector-length-agnostic daxpy: pads to the chosen VL, runs the
     predicated kernel, returns the first len(x) elements.  ``n`` defaults to
     the full length; any n <= len(x) exercises the predicated tail."""
@@ -21,5 +21,5 @@ def daxpy(x, y, a, n=None, *, block: int | None = None, interpret: bool = True):
     if padded != length:
         x = jnp.pad(x, (0, padded - length))
         y = jnp.pad(y, (0, padded - length))
-    out = daxpy_pallas(x, y, a, n, block=block, interpret=interpret)
+    out = daxpy_pallas(x, y, a, n, block=block)
     return out[:length]

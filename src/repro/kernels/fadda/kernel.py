@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 
 def _fadda_kernel(n_ref, x_ref, o_ref, acc_scr, *, block: int, n_tiles: int):
     pid = pl.program_id(0)
@@ -43,8 +45,8 @@ def _fadda_kernel(n_ref, x_ref, o_ref, acc_scr, *, block: int, n_tiles: int):
         o_ref[0, 0] = acc_scr[0, 0]
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def fadda_pallas(x, n, *, block: int = 512, interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("block",))
+def fadda_pallas(x, n, *, block: int = 512):
     padded = x.shape[0]
     assert padded % block == 0
     n_tiles = padded // block
@@ -59,6 +61,6 @@ def fadda_pallas(x, n, *, block: int = 512, interpret: bool = True):
         out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
         scratch_shapes=[pltpu.SMEM((1, 1), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(),
     )(jnp.asarray([n], jnp.int32), x.reshape(1, padded))
     return out[0, 0]

@@ -26,11 +26,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -1e30  # finite stand-in: keeps exp/where NaN-free in f32
 
 
 def _flash_kernel(
-    # scalar-prefetch style operands (full arrays in ANY memory space)
+    # scalar-prefetch operands (SMEM)
     kvlen_ref, qoff_ref, win_ref,
     # blocked operands
     q_ref, k_ref, v_ref,
@@ -52,10 +54,14 @@ def _flash_tile(
     k, v,                                          # (bk, D) f32 tiles, loaded
     o_ref, m_scr, l_scr, acc_scr,
     *, bq: int, bk: int, n_kv: int, causal: bool, scale: float,
+    k_col=None, v_col=None,
 ):
     """The shared online-softmax tile body.  K/V tiles arrive as loaded f32
     arrays so callers may widen narrow (quantized) storage on the way in —
     the in-register half of SVE's extending load — without forking the math.
+    ``k_col`` / ``v_col`` are optional (1, bk) per-token scales of such narrow
+    tiles, applied to the logit / probability COLUMNS (q·(s·k)ᵀ = (q·kᵀ)·s),
+    so the widening needs no lane-to-sublane relayout of the scale row.
     """
     b = pl.program_id(0)
     iq = pl.program_id(2)
@@ -71,6 +77,8 @@ def _flash_tile(
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale  # (bq, bk)
+    if k_col is not None:
+        s = s * k_col
 
     # ---- the governing predicate (whilelt algebra; paper §2.3) ----
     qpos = (qoff_ref[b] + iq * bq
@@ -93,8 +101,9 @@ def _flash_tile(
     p = jnp.where(pred, jnp.exp(s - m_new), 0.0)    # zeroing predication
     l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
 
+    pv = p if v_col is None else p * v_col
     acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        pv, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
     l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
@@ -144,19 +153,23 @@ def _flash_kernel_paged_quant(
     # VMEM scratch
     m_scr, l_scr, acc_scr,
     *, bq: int, page_size: int, n_pages: int, causal: bool, scale: float,
+    group: int,
 ):
-    """Quantized paged tile: the scale rows arrive through the SAME
-    table-driven index_map as the K/V page, and the narrow elements widen in
-    register (``q8 * scale`` per token row) before the unchanged softmax body
-    — SVE §2.3.3's extending gather-load at the block-fetch level."""
+    """Quantized paged tile: the page's (Hkv, ps) scale slab arrives through
+    the SAME table-driven index_map as the K/V page, the kernel picks its KV
+    head's row, and the narrow elements widen in register (``q8 * scale``
+    per token, folded into the logit / probability columns) around the
+    unchanged softmax body — SVE §2.3.3's extending gather-load at the
+    block-fetch level."""
     del table_ref                                  # consumed by the index_maps
-    k = (k_ref[0, 0].astype(jnp.float32)
-         * ks_ref[0, 0].astype(jnp.float32)[:, None])
-    v = (v_ref[0, 0].astype(jnp.float32)
-         * vs_ref[0, 0].astype(jnp.float32)[:, None])
-    _flash_tile(kvlen_ref, qoff_ref, win_ref, q_ref, k, v, o_ref,
+    kvh = pl.program_id(1) // group
+    _flash_tile(kvlen_ref, qoff_ref, win_ref, q_ref,
+                k_ref[0, 0].astype(jnp.float32),
+                v_ref[0, 0].astype(jnp.float32), o_ref,
                 m_scr, l_scr, acc_scr, bq=bq, bk=page_size, n_kv=n_pages,
-                causal=causal, scale=scale)
+                causal=causal, scale=scale,
+                k_col=ks_ref[pl.ds(kvh, 1), :].astype(jnp.float32),
+                v_col=vs_ref[pl.ds(kvh, 1), :].astype(jnp.float32))
 
 
 @functools.partial(
@@ -165,7 +178,7 @@ def _flash_kernel_paged_quant(
 def flash_attention_pallas_paged(
     q, k_pool, v_pool, page_table, kv_lens, q_offset, window,
     *, bq: int = 256, causal: bool = False,
-    scale: float | None = None, interpret: bool = True,
+    scale: float | None = None, interpret: bool | None = None,
     k_scale=None, v_scale=None,
 ):
     """q: (B, Hq, Sq, D) with Sq % bq == 0; k_pool/v_pool: (P, Hkv, ps, D);
@@ -178,7 +191,8 @@ def flash_attention_pallas_paged(
 
     ``k_scale`` / ``v_scale``: ``(P, Hkv, ps)`` per-slot scale pools of a
     QUANTIZED cache; their (1, 1, ps) blocks ride the same table-driven
-    index_map and the kernel widens the narrow K/V in register."""
+    index_map as whole-page ``(Hkv, ps)`` slabs (a legal (8, 128) tiling at
+    any head count), and the kernel widens the narrow K/V in register."""
     bsz, hq, sq, d = q.shape
     hkv, ps = k_pool.shape[1], k_pool.shape[2]
     n_pages = page_table.shape[1]
@@ -188,9 +202,13 @@ def flash_attention_pallas_paged(
     scale = (d ** -0.5) if scale is None else scale
     quant = k_scale is not None
 
-    kern = _flash_kernel_paged_quant if quant else _flash_kernel_paged
     kernel = functools.partial(
-        kern, bq=bq, page_size=ps, n_pages=n_pages, causal=causal, scale=scale)
+        _flash_kernel_paged, bq=bq, page_size=ps, n_pages=n_pages,
+        causal=causal, scale=scale)
+    if quant:
+        kernel = functools.partial(
+            _flash_kernel_paged_quant, bq=bq, page_size=ps, n_pages=n_pages,
+            causal=causal, scale=scale, group=group)
 
     def q_map(b, h, i, j, table, kvl, qo, win):
         return (b, h, i, 0)
@@ -199,7 +217,7 @@ def flash_attention_pallas_paged(
         return (table[b, j], h // group, 0, 0)     # the gather: index vector
 
     def sc_map(b, h, i, j, table, kvl, qo, win):
-        return (table[b, j], h // group, 0)        # scale rows: same walk
+        return (table[b, j], 0, 0)                 # scale slab: same walk
 
     in_specs = [
         pl.BlockSpec((1, 1, bq, d), q_map),
@@ -208,8 +226,8 @@ def flash_attention_pallas_paged(
     ]
     operands = [q, k_pool, v_pool]
     if quant:
-        in_specs += [pl.BlockSpec((1, 1, ps), sc_map),
-                     pl.BlockSpec((1, 1, ps), sc_map)]
+        in_specs += [pl.BlockSpec((None, hkv, ps), sc_map),
+                     pl.BlockSpec((None, hkv, ps), sc_map)]
         operands += [k_scale, v_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -227,7 +245,7 @@ def flash_attention_pallas_paged(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(page_table.astype(jnp.int32), kv_lens, q_offset, window, *operands)
 
 
@@ -237,13 +255,14 @@ def flash_attention_pallas_paged(
 def flash_attention_pallas(
     q, k, v, kv_lens, q_offset, window,
     *, bq: int = 256, bk: int = 512, causal: bool = False,
-    scale: float | None = None, interpret: bool = True,
+    scale: float | None = None, interpret: bool | None = None,
 ):
     """q: (B, Hq, Sq, D) with Sq % bq == 0; k/v: (B, Hkv, Skv, D), Skv % bk == 0.
 
     kv_lens: (B,) int32 valid KV length per row; q_offset: (B,) int32 absolute
-    position of q[:, :, 0] (decode against a cache).  See ops.flash_attention
-    for the padding/VL-selection wrapper.
+    position of q[:, :, 0] (decode against a cache).  The three scalars ride
+    scalar prefetch into SMEM.  See ops.flash_attention for the padding/VL-
+    selection wrapper.
     """
     bsz, hq, sq, d = q.shape
     hkv, skv = k.shape[1], k.shape[2]
@@ -255,24 +274,30 @@ def flash_attention_pallas(
     kernel = functools.partial(
         _flash_kernel, bq=bq, bk=bk, n_kv=n_kv, causal=causal, scale=scale)
 
-    grid = (bsz, hq, n_q, n_kv)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
+    def q_map(b, h, i, j, kvl, qo, win):
+        return (b, h, i, 0)
+
+    def kv_map(b, h, i, j, kvl, qo, win):
+        return (b, h // group, j, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,                     # kv_lens, q_offset, window
+        grid=(bsz, hq, n_q, n_kv),
         in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),      # kv_lens
-            pl.BlockSpec(memory_space=pl.ANY),      # q_offset
-            pl.BlockSpec(memory_space=pl.ANY),      # window (dynamic)
-            pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b, h, i, j: (b, h // group, j, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b, h, i, j: (b, h // group, j, 0)),
+            pl.BlockSpec((1, 1, bq, d), q_map),
+            pl.BlockSpec((1, 1, bk, d), kv_map),
+            pl.BlockSpec((1, 1, bk, d), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_specs=pl.BlockSpec((1, 1, bq, d), q_map),
         scratch_shapes=[
             pltpu.VMEM((bq, 128), jnp.float32),     # m (running max)
             pltpu.VMEM((bq, 128), jnp.float32),     # l (running denominator)
             pltpu.VMEM((bq, d), jnp.float32),       # acc (unnormalized output)
         ],
-        interpret=interpret,
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        interpret=resolve_interpret(interpret),
     )(kv_lens, q_offset, window, q, k, v)

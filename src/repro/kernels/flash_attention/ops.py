@@ -33,7 +33,7 @@ def flash_attention(
     *, kv_lens=None, causal: bool = False, window: int | None = None,
     q_offset=None, scale: float | None = None,
     impl: str = "kernel", bq: int | None = None, bk: int | None = None,
-    interpret: bool = True, page_table=None, k_scale=None, v_scale=None,
+    page_table=None, k_scale=None, v_scale=None,
 ):
     """Predicated attention.  q: (B, Hq, Sq, D); k, v: (B, Hkv, Skv, D).
 
@@ -41,7 +41,7 @@ def flash_attention(
     - ``causal`` / ``window``: mask predicates (window = sliding local size).
     - ``q_offset``: (B,) absolute position of the first query row (decode);
       defaults to Skv - Sq under ``causal`` (suffix alignment) else 0.
-    - ``impl``: "kernel" (Pallas TPU; interpret=True on CPU), "xla" (chunked
+    - ``impl``: "kernel" (Pallas TPU; interpreted on CPU), "xla" (chunked
       lax.scan flash with custom VJP — the introspectable O(S)-memory path the
       dry-run lowers), or "naive" (quadratic oracle; tests only).
     - ``page_table``: (B, n_pages) int32 — PAGED mode: ``k``/``v`` are page
@@ -55,7 +55,6 @@ def flash_attention(
         return _flash_paged(q, k, v, page_table, kv_lens=kv_lens,
                             causal=causal, window=window, q_offset=q_offset,
                             scale=scale, impl=impl, bq=bq,
-                            interpret=interpret,
                             k_scale=k_scale, v_scale=v_scale)
     assert k_scale is None and v_scale is None, \
         "quantized K/V scales require page_table (paged mode)"
@@ -97,13 +96,12 @@ def flash_attention(
     else:
         out = flash_attention_pallas(
             q, k, v, kv_lens, q_offset, win, bq=bq, bk=bk, causal=causal,
-            scale=scale, interpret=interpret)
+            scale=scale)
     return out[:, :, :sq, :]
 
 
 def _flash_paged(q, k_pool, v_pool, page_table, *, kv_lens, causal, window,
-                 q_offset, scale, impl, bq, interpret,
-                 k_scale=None, v_scale=None):
+                 q_offset, scale, impl, bq, k_scale=None, v_scale=None):
     """Paged dispatch: pools + page table instead of dense K/V."""
     b, hq, sq, d = q.shape
     ps = k_pool.shape[2]
@@ -151,6 +149,6 @@ def _flash_paged(q, k_pool, v_pool, page_table, *, kv_lens, causal, window,
     else:
         out = flash_attention_pallas_paged(
             q, k_pool, v_pool, page_table, kv_lens, q_offset, win,
-            bq=bq, causal=causal, scale=scale_f, interpret=interpret,
+            bq=bq, causal=causal, scale=scale_f,
             k_scale=k_scale, v_scale=v_scale)
     return out[:, :, :sq, :]

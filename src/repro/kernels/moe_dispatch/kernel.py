@@ -15,6 +15,11 @@ observe overflow statistics (aux losses need them).
 
 Tile geometry: tokens_per_tile x E one-hot in int32; for E=64..128 and tile
 512 that is a 512x128 i32 buffer = 256 KiB — VMEM-friendly, lane-aligned.
+
+Off the chip path: Mosaic has no lowering for ``cumsum``, so this kernel
+runs only interpreted on the CPU, and no model calls it (``models/moe.py``
+routes through the XLA reference).  A dropless expert layer is to replace
+capacity dispatch altogether (ROADMAP, Reach item 4).
 """
 
 from __future__ import annotations
@@ -27,6 +32,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import vla
+from repro.kernels import resolve_interpret
 
 
 def _dispatch_kernel(ids_ref, pos_ref, counts_ref, counts_scr,
@@ -51,9 +57,8 @@ def _dispatch_kernel(ids_ref, pos_ref, counts_ref, counts_scr,
         counts_ref[...] = counts_scr[0:1, :]
 
 
-@functools.partial(jax.jit, static_argnames=("n_experts", "tile", "interpret"))
-def moe_positions_pallas(expert_ids, *, n_experts: int, tile: int = 512,
-                         interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("n_experts", "tile"))
+def moe_positions_pallas(expert_ids, *, n_experts: int, tile: int = 512):
     """expert_ids: (T, K) int32; T % tile == 0 (ops.py pads with -1).
     Returns pos (T, K) int32 and counts (E,) int32."""
     t, k = expert_ids.shape
@@ -75,6 +80,6 @@ def moe_positions_pallas(expert_ids, *, n_experts: int, tile: int = 512,
             jax.ShapeDtypeStruct((1, e_pad), jnp.int32),
         ],
         scratch_shapes=[pltpu.VMEM((8, e_pad), jnp.int32)],
-        interpret=interpret,
+        interpret=resolve_interpret(),
     )(expert_ids)
     return pos, counts[0, :n_experts]

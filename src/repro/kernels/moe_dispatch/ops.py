@@ -17,7 +17,7 @@ from .ref import moe_positions_ref
 
 
 def moe_positions(expert_ids, n_experts: int, *, tile: int = 512,
-                  impl: str = "kernel", interpret: bool = True):
+                  impl: str = "kernel"):
     """Rank of each (token, slot) assignment within its expert + totals."""
     t, k = expert_ids.shape
     if impl == "xla":
@@ -26,13 +26,12 @@ def moe_positions(expert_ids, n_experts: int, *, tile: int = 512,
     ids = expert_ids
     if t_pad != t:
         ids = jnp.pad(ids, ((0, t_pad - t), (0, 0)), constant_values=-1)
-    pos, counts = moe_positions_pallas(ids, n_experts=n_experts, tile=tile,
-                                       interpret=interpret)
+    pos, counts = moe_positions_pallas(ids, n_experts=n_experts, tile=tile)
     return pos[:t], counts
 
 
 def build_dispatch(expert_ids, gates, n_experts: int, capacity: int,
-                   *, impl: str = "kernel", interpret: bool = True):
+                   *, impl: str = "kernel"):
     """Build the dispatch tables for a capacity-C MoE layer.
 
     Returns dict with:
@@ -47,8 +46,7 @@ def build_dispatch(expert_ids, gates, n_experts: int, capacity: int,
       dropped:     ()  int32   — number of dropped assignments.
     """
     t, k = expert_ids.shape
-    pos, counts = moe_positions(expert_ids, n_experts, impl=impl,
-                                interpret=interpret)
+    pos, counts = moe_positions(expert_ids, n_experts, impl=impl)
     valid = (expert_ids >= 0) & (expert_ids < n_experts)
     keep = valid & (pos < capacity)
 

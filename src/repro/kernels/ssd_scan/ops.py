@@ -11,7 +11,7 @@ from .ref import ssd_chunked_ref, ssd_ref  # noqa: F401  (oracle re-export)
 
 
 def ssd_scan(x, dt, A, B, C, D=None, *, seq_lens=None, h0=None,
-             chunk: int = 128, impl: str = "kernel", interpret: bool = True):
+             chunk: int = 128, impl: str = "kernel"):
     """Chunk-size-agnostic SSD scan.
 
     x: (Bz, S, H, P); dt: (Bz, S, H) (positive; e.g. softplus upstream);
@@ -46,7 +46,7 @@ def ssd_scan(x, dt, A, B, C, D=None, *, seq_lens=None, h0=None,
         if h0 is None:
             h0 = jnp.zeros((bz, h, p, B.shape[-1]), jnp.float32)
         y, hT = ssd_scan_pallas(x, dt, A, B, C, h0.astype(jnp.float32),
-                                chunk=chunk, interpret=interpret)
+                                chunk=chunk)
 
     y = y[:, :s]
     if D is not None:

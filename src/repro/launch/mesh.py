@@ -62,7 +62,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"XLA_FLAGS=--xla_force_host_platform_device_count={want})",
             RuntimeWarning, stacklevel=2)
     need = math.prod(shape)
-    return jax.make_mesh(tuple(shape), axes, devices=devs[:need])
+    return _auto_mesh(tuple(shape), axes, devs[:need])
 
 
 def make_mesh(shape, axes):
@@ -71,7 +71,15 @@ def make_mesh(shape, axes):
     devs = jax.devices()
     if len(devs) < need:
         raise RuntimeError(f"mesh {shape} needs {need} devices, have {len(devs)}")
-    return jax.make_mesh(shape, axes, devices=devs[:need])
+    return _auto_mesh(tuple(shape), axes, devs[:need])
+
+
+def _auto_mesh(shape, axes, devices):
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding rules place
+    arrays through ``with_sharding_constraint`` and leave propagation to
+    GSPMD, which the default *explicit* axis types (JAX >= 0.7) reject."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def batch_shard_count(mesh) -> int:

@@ -1,8 +1,15 @@
-"""Serving launcher: bring up a ServeEngine for an arch (reduced dims on CPU)
-and push a stream of ragged requests through the continuous-batching
-scheduler (default), or a single static batch with --static.
+"""Serving launcher: bring up a ServeEngine for an arch and push a stream of
+ragged requests through the continuous-batching scheduler (default), or a
+single static batch with --static.
+
+The architecture's published config is served by default, with weights held
+in its compute dtype (bf16: serving keeps no f32 master copy).  ``--reduce``
+cuts it to 2 layers x d64 in float32 for a CPU rehearsal.
 
     PYTHONPATH=src python -m repro.launch.serve --arch stablelm_3b --reduce
+
+``main(argv)`` is also the in-process entry point (``chip_smoke.py``): a
+process that has touched JAX holds the chip, so a child could not serve.
 """
 
 from __future__ import annotations
@@ -17,6 +24,7 @@ from repro.configs import all_arch_names, get_config
 from repro.dist import collectives as C
 from repro.models import get_model
 from repro.obs import Obs, Tracer
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serve import (
     ChaosConfig,
     ChaosMonkey,
@@ -26,13 +34,23 @@ from repro.serve import (
 )
 
 from .mesh import force_host_devices, make_mesh, parse_mesh
-from .train import REDUCE
+from .train import reduce_config
 
 
-def main():
+def main(argv=None):
+    """Parse ``argv`` (default: ``sys.argv``), serve, print per-request
+    lines, and return ``{"results", "stats", "prompts", "engine"}``:
+    results by request id, the scheduler's stats (None under --static), the
+    prompt of each request id, and the engine (config + params)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="stablelm_3b", choices=all_arch_names())
-    ap.add_argument("--reduce", action="store_true", default=True)
+    ap.add_argument("--reduce", action="store_true",
+                    help="CPU rehearsal: cut the architecture to 2 layers x "
+                         "d64 in float32 (default: the published config)")
+    ap.add_argument("--attn-impl", choices=["xla", "kernel"], default=None,
+                    help="attention implementation (default: the config's); "
+                         "'kernel' runs the Pallas flash kernels, compiled "
+                         "on an accelerator and interpreted on the CPU")
     ap.add_argument("--batch", type=int, default=4,
                     help="lane capacity (scheduler) / batch size (--static)")
     ap.add_argument("--requests", type=int, default=10,
@@ -146,7 +164,8 @@ def main():
                     help="wrap dispatch-seam spans in jax.profiler."
                          "TraceAnnotation so a concurrently captured XLA "
                          "device profile interleaves with the host timeline")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     C.set_psum_mode(args.psum)
     obs = Obs(tracer=Tracer() if args.trace_out else None,
@@ -170,21 +189,16 @@ def main():
                               seed=args.sample_seed + i, greedy=False)
 
     cfg = get_config(args.arch)
-    over = dict(REDUCE)
-    if cfg.family in ("ssm", "hybrid"):
-        over.update(ssm_state=16, ssm_headdim=16, ssm_chunk=16)
-    if cfg.family == "moe":
-        over.update(n_experts=8, top_k=2, d_ff_dense=128)
-    if cfg.family == "encdec":
-        over.update(n_enc_layers=2, n_dec_layers=2)
-    if cfg.family == "hybrid":
-        over.update(n_layers=5, shared_attn_period=2)
-    if cfg.cross_attn_group:
-        over.update(n_layers=10)
-    cfg = cfg.replace(**over)
+    # full width serves from weights in the compute dtype (no f32 master)
+    cfg = (reduce_config(cfg) if args.reduce
+           else cfg.replace(param_dtype=cfg.compute_dtype))
+    if args.attn_impl is not None:
+        cfg = cfg.replace(attn_impl=args.attn_impl)
 
     model = get_model(cfg)
-    params, _ = model.init(jax.random.PRNGKey(0), cfg)
+    # one jitted init: the weights materialize in their own dtype, with no
+    # f32 staging copy of a whole layer stack on the device
+    params = jax.jit(lambda k: model.init(k, cfg)[0])(jax.random.PRNGKey(0))
     rng = np.random.RandomState(0)
     batch = {"tokens": jnp.asarray(
         rng.randint(1, cfg.vocab_size, (args.batch, args.prompt_len))),
@@ -212,12 +226,17 @@ def main():
         # vlm cross_emb extras are per-batch, not yet per-request: static path
         res = eng.generate(batch, sampling=[_sampling(i)
                                             for i in range(args.batch)])
+        results, prompts = {}, {}
         for i in range(args.batch):
             n = int(res["n_generated"][i])
+            results[i] = {"tokens": np.asarray(res["tokens"][i, :n]),
+                          "n_generated": n}
+            prompts[i] = np.asarray(batch["tokens"][i, :int(batch["lens"][i])])
             print(f"req{i} len={int(batch['lens'][i]):2d} -> "
-                  f"{res['tokens'][i, :n].tolist()}")
+                  f"{results[i]['tokens'].tolist()}")
         _finish_obs(args, obs)
-        return
+        return {"results": results, "stats": None, "prompts": prompts,
+                "engine": eng}
 
     # ---- continuous batching: stream requests through the lane vector ----
     max_len = args.prompt_len + args.max_new
@@ -239,7 +258,7 @@ def main():
             alloc_fail_rate=args.chaos_alloc_fail_rate,
             cancel_rate=args.chaos_cancel_rate,
             swap_corrupt_rate=args.chaos_swap_corrupt_rate)).install(sched)
-    rid_len = {}
+    prompts = {}
     for i in range(args.requests):
         plen = int(rng.randint(4, args.prompt_len + 1))
         extras = None
@@ -249,16 +268,16 @@ def main():
                       .astype(np.float32)}
         prio = (5 if args.priority_every and i % args.priority_every == 0
                 else 0)
-        rid = sched.submit(rng.randint(1, cfg.vocab_size, plen),
-                           sampling=_sampling(i), extras=extras,
+        prompt = rng.randint(1, cfg.vocab_size, plen)
+        rid = sched.submit(prompt, sampling=_sampling(i), extras=extras,
                            priority=prio,
                            deadline=(args.deadline_steps
                                      if args.deadline_steps else None))
-        rid_len[rid] = plen
+        prompts[rid] = prompt
     results = monkey.run(sched) if monkey else sched.run()
     for rid in sorted(results):
         r = results[rid]
-        print(f"req{rid} len={rid_len[rid]:2d} "
+        print(f"req{rid} len={len(prompts[rid]):2d} "
               f"[{r['finish_reason'].value}] -> "
               f"{r['tokens'].tolist()}")
     occ = sched.stats["occupancy_trace"]
@@ -299,6 +318,8 @@ def main():
                   f"checksum failures="
                   f"{sched.stats['swap_checksum_failures']}")
     _finish_obs(args, obs)
+    return {"results": results, "stats": sched.stats, "prompts": prompts,
+            "engine": eng}
 
 
 def _finish_obs(args, obs):

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from repro.configs import all_arch_names, get_config
 from repro.data import SyntheticLM
 from repro.runtime import FaultTolerantLoop
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.train.step import init_state, make_train_step
 
 REDUCE = dict(n_layers=2, d_model=64, d_ff=128, vocab_size=256, n_heads=4,
@@ -27,7 +28,25 @@ REDUCE = dict(n_layers=2, d_model=64, d_ff=128, vocab_size=256, n_heads=4,
               param_dtype="float32", compute_dtype="float32")
 
 
-def main():
+def reduce_config(cfg):
+    """Shrink an architecture's dims for a CPU rehearsal, keeping its family
+    and topology (layer pattern, expert routing, encoder/decoder split)."""
+    over = dict(REDUCE)
+    if cfg.family in ("ssm", "hybrid"):
+        over.update(ssm_state=16, ssm_headdim=16, ssm_chunk=16)
+    if cfg.family == "moe":
+        over.update(n_experts=8, top_k=2,
+                    d_ff_dense=128 if cfg.first_k_dense else None)
+    if cfg.family == "encdec":
+        over.update(n_enc_layers=2, n_dec_layers=2)
+    if cfg.family == "hybrid":
+        over.update(n_layers=5, shared_attn_period=2)
+    if cfg.cross_attn_group:
+        over.update(n_layers=10)
+    return cfg.replace(**{k: v for k, v in over.items() if v is not None})
+
+
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mamba2_130m", choices=all_arch_names())
     ap.add_argument("--steps", type=int, default=20)
@@ -37,23 +56,12 @@ def main():
                     help="shrink dims for CPU (keeps family/topology)")
     ap.add_argument("--microbatch", type=int, default=1)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_launch_train")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduce:
-        over = dict(REDUCE)
-        if cfg.family in ("ssm", "hybrid"):
-            over.update(ssm_state=16, ssm_headdim=16, ssm_chunk=16)
-        if cfg.family == "moe":
-            over.update(n_experts=8, top_k=2,
-                        d_ff_dense=128 if cfg.first_k_dense else None)
-        if cfg.family == "encdec":
-            over.update(n_enc_layers=2, n_dec_layers=2)
-        if cfg.family == "hybrid":
-            over.update(n_layers=5, shared_attn_period=2)
-        if cfg.cross_attn_group:
-            over.update(n_layers=10)
-        cfg = cfg.replace(**{k: v for k, v in over.items() if v is not None})
+        cfg = reduce_config(cfg)
     print(f"arch={cfg.name} family={cfg.family} params={cfg.param_count():.3e}")
 
     state, _ = init_state(jax.random.PRNGKey(0), cfg)

@@ -26,7 +26,6 @@ function of (seed, prompt, params) only, never of batch composition.
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from typing import Optional, Sequence
 
 import jax
@@ -165,7 +164,6 @@ class ServeEngine:
                          "_fused_step", "_gather_blocks", "_scatter_blocks",
                          "_spill_lane", "_resume_lane"):
                 setattr(self, name, self._with_mesh(getattr(self, name)))
-        self._warned_gather_fallback = False
 
     def _with_mesh(self, fn):
         def run(*args, **kwargs):
@@ -319,16 +317,15 @@ class ServeEngine:
         if not is_paged(cache):
             return self.model.decode(params, self.cfg, batch, cache)
         if self.paged_attn != "gather":
-            if paged_decode_ok(self.cfg):
-                return self.model.decode(params, self.cfg, batch, cache)
-            if not self._warned_gather_fallback:
-                # trace-time emission: fires once per engine, not per step
-                warnings.warn(
+            if not paged_decode_ok(self.cfg):
+                # every pageable family decodes natively: a reference path
+                # quietly standing in for the kernel path is a bug, not a
+                # fallback (raised at trace time)
+                raise NotImplementedError(
                     f"family '{self.cfg.family}' has no native paged decode; "
-                    "falling back to the gather bridge (dense view "
-                    "materialized through the page table every step)",
-                    RuntimeWarning, stacklevel=2)
-                self._warned_gather_fallback = True
+                    "select the gather oracle explicitly with "
+                    "paged_attn='gather'")
+            return self.model.decode(params, self.cfg, batch, cache)
         view = paged_view(self.cfg, cache)
         pos = view["pos"]
         logits, view = self.model.decode(params, self.cfg, batch, view)

@@ -14,15 +14,9 @@ from repro.dist.collectives import (ordered_psum, pairwise_psum,
                                     compressed_psum, psum, set_psum_mode)
 from repro.launch.mesh import make_mesh
 
-if hasattr(jax, "shard_map"):                      # jax >= 0.6
-    def smap(f, mesh, in_specs, out_specs):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-else:                                              # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    def smap(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+def smap(f, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 mesh = make_mesh((8,), ("data",))
 rng = np.random.RandomState(0)
@@ -105,9 +99,7 @@ def test_collectives_on_submesh():
     r = subprocess.run([sys.executable, "-c", SCRIPT], capture_output=True,
                        text=True, timeout=300,
                        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                            # force CPU: without this jax probes for
-                            # accelerator plugins and can hang on
-                            # network lookups in the bare subprocess
+                            # tests run on the CPU backend
                             "JAX_PLATFORMS": "cpu",
                             "HOME": "/root"})
     assert r.returncode == 0, r.stdout + r.stderr

@@ -87,13 +87,8 @@ def inner(x):
         return jax.lax.psum(c, "data"), None
     return jax.lax.scan(body, x, None, length=7)[0]
 
-if hasattr(jax, "shard_map"):                      # jax >= 0.6
-    f = jax.shard_map(inner, mesh=mesh, in_specs=P(), out_specs=P(),
-                      check_vma=False)
-else:                                              # jax 0.4.x
-    from jax.experimental.shard_map import shard_map
-    f = shard_map(inner, mesh=mesh, in_specs=P(), out_specs=P(),
-                  check_rep=False)
+f = jax.shard_map(inner, mesh=mesh, in_specs=P(), out_specs=P(),
+                  check_vma=False)
 c = jax.jit(f).lower(jnp.zeros((64, 64))).compile()
 a = analyze(c.as_text())
 per = 64 * 64 * 4
@@ -104,9 +99,7 @@ print("COLLECTIVE-TRIPS-OK")
     r = subprocess.run([sys.executable, "-c", script], capture_output=True,
                        text=True, timeout=300,
                        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                            # force CPU: without this jax probes for
-                            # accelerator plugins and can hang on
-                            # network lookups in the bare subprocess
+                            # tests run on the CPU backend
                             "JAX_PLATFORMS": "cpu",
                             "HOME": "/root"})
     assert "COLLECTIVE-TRIPS-OK" in r.stdout, r.stdout + r.stderr

@@ -543,24 +543,18 @@ def test_moe_family_paged_native_bit_identity():
     assert sched.allocator.free_pages == sched.pool_pages
 
 
-def test_gather_fallback_warns_once():
-    """A family without native paged decode under the native default emits
-    ONE RuntimeWarning and still serves through the gather bridge."""
+def test_gather_fallback_raises(monkeypatch):
+    """A family without native paged decode under the native default is an
+    error: the gather oracle never quietly stands in for the native path."""
     cfg = _family_cfg("dense")
     model = get_model(cfg)
     params, _ = model.init(jax.random.PRNGKey(0), cfg)
     eng = ServeEngine(cfg, params, max_new_tokens=4, stop_token=7)
-    monkey = pytest.MonkeyPatch()
-    try:
-        import repro.models.dense as D
-        monkey.setattr(D, "paged_decode_ok", lambda cfg: False)
-        with pytest.warns(RuntimeWarning, match="gather bridge"):
-            res = eng.generate({"tokens": jnp.asarray([[3, 4, 5, 6]])},
-                               max_len=16, page_size=8)
-        assert int(res["n_generated"][0]) >= 1
-        assert eng._warned_gather_fallback
-    finally:
-        monkey.undo()
+    import repro.models.dense as D
+    monkeypatch.setattr(D, "paged_decode_ok", lambda cfg: False)
+    with pytest.raises(NotImplementedError, match="no native paged decode"):
+        eng.generate({"tokens": jnp.asarray([[3, 4, 5, 6]])},
+                     max_len=16, page_size=8)
 
 
 def test_ssm_family_refuses_paging():

@@ -84,9 +84,7 @@ def test_sharded_decode_collective_budget():
     r = subprocess.run([sys.executable, "-c", SCRIPT], capture_output=True,
                        text=True, timeout=580,
                        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                            # force CPU: without this jax probes for
-                            # accelerator plugins and can hang on
-                            # network lookups in the bare subprocess
+                            # tests run on the CPU backend
                             "JAX_PLATFORMS": "cpu",
                             "HOME": "/root"})
     assert r.returncode == 0, r.stdout + r.stderr

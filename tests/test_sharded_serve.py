@@ -15,8 +15,7 @@ import sys
 import pytest
 
 _ENV = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-        # force CPU: without this jax probes for accelerator plugins and
-        # can hang on network lookups in the bare subprocess
+        # tests run on the CPU backend
         "JAX_PLATFORMS": "cpu", "HOME": "/root"}
 
 _PRELUDE = r"""

@@ -98,9 +98,7 @@ def test_sharding_rules_subprocess():
     r = subprocess.run([sys.executable, "-c", SCRIPT], capture_output=True,
                        text=True, timeout=300,
                        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                            # force CPU: without this jax probes for
-                            # accelerator plugins and can hang on
-                            # network lookups in the bare subprocess
+                            # tests run on the CPU backend
                             "JAX_PLATFORMS": "cpu",
                             "HOME": "/root"})
     assert r.returncode == 0, r.stdout + r.stderr

@@ -80,9 +80,7 @@ print("ELASTIC-OK")
     r = subprocess.run([sys.executable, "-c", script], capture_output=True,
                        text=True, timeout=300,
                        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                            # force CPU: without this jax probes for
-                            # accelerator plugins and can hang on
-                            # network lookups in the bare subprocess
+                            # tests run on the CPU backend
                             "JAX_PLATFORMS": "cpu",
                             "HOME": "/root"})
     assert "ELASTIC-OK" in r.stdout, r.stdout + r.stderr
