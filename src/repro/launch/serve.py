@@ -161,9 +161,12 @@ def main(argv=None):
                          "counter/percentile dict the serving bench records "
                          "per leg) after the run")
     ap.add_argument("--xla-annotations", action="store_true",
-                    help="wrap dispatch-seam spans in jax.profiler."
-                         "TraceAnnotation so a concurrently captured XLA "
-                         "device profile interleaves with the host timeline")
+                    help="make every serve-loop span (round, plan, "
+                         "admit, dispatch, harvest, sync, ...) a jax."
+                         "profiler.TraceAnnotation, with or without "
+                         "--trace-out, so a concurrently captured XLA "
+                         "device profile holds the host's rounds on its "
+                         "clock")
     args = ap.parse_args(argv)
     enable_compile_cache()
 

@@ -18,6 +18,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.obs.scopes import scope
+
 from . import layers as L
 
 NO_WINDOW = 2 ** 30
@@ -295,11 +297,13 @@ def prefill(params, cfg, batch, cache):
         cache["k"], cache["v"] = k_new, v_new
 
     cache["pos"] = pos0 + lens
-    h = L.apply_norm(params["final_norm"], h, cfg)
-    # logits at each row's last valid position (ragged gather)
-    idx = jnp.clip(lens - 1, 0, s - 1)
-    h_last = jnp.take_along_axis(h, idx[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    logits = L.unembed(params["embed"], h_last[:, None], cfg)[:, 0]
+    with scope("logits"):
+        h = L.apply_norm(params["final_norm"], h, cfg)
+        # logits at each row's last valid position (ragged gather)
+        idx = jnp.clip(lens - 1, 0, s - 1)
+        h_last = jnp.take_along_axis(
+            h, idx[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+        logits = L.unembed(params["embed"], h_last[:, None], cfg)[:, 0]
     return logits, cache
 
 
@@ -345,11 +349,12 @@ def decode(params, cfg, batch, cache):
                 h, new_kv = L.block_apply(
                     lp, h, positions, cfg, causal=False, kv_lens=pos + 1,
                     q_offset=pos, cache=layer_cache, cache_pos=pos)
-                kc = kc.at[gi, si].set(new_kv[0])
-                vc = vc.at[gi, si].set(new_kv[1])
-                if ksc is not None:
-                    ksc = ksc.at[gi, si].set(new_kv[2])
-                    vsc = vsc.at[gi, si].set(new_kv[3])
+                with scope("kv_write"):
+                    kc = kc.at[gi, si].set(new_kv[0])
+                    vc = vc.at[gi, si].set(new_kv[1])
+                    if ksc is not None:
+                        ksc = ksc.at[gi, si].set(new_kv[2])
+                        vsc = vsc.at[gi, si].set(new_kv[3])
         cache = dict(cache)
         if paged:
             cache["k_pages"], cache["v_pages"] = kc, vc
@@ -368,18 +373,20 @@ def decode(params, cfg, batch, cache):
         table = cache["page_table"]
         dus = jax.lax.dynamic_update_slice_in_dim
         for li in range(cfg.n_layers):
-            lp = jax.tree.map(lambda a, li=li: a[li], params["blocks"])
-            layer_cache = ((kp[li], vp[li], table) if ksc is None
-                           else (kp[li], vp[li], table, ksc[li], vsc[li]))
+            lp = _layer_params(params["blocks"], li)
+            with scope("kv_write"):     # the layer's pools, out and back in
+                layer_cache = ((kp[li], vp[li], table) if ksc is None else
+                               (kp[li], vp[li], table, ksc[li], vsc[li]))
             h, new_kv = L.block_apply(
                 lp, h, positions, cfg, causal=False, window=wins[li],
                 kv_lens=pos + 1, q_offset=pos, cache=layer_cache,
                 cache_pos=pos)
-            kp = dus(kp, new_kv[0][None], li, axis=0)
-            vp = dus(vp, new_kv[1][None], li, axis=0)
-            if ksc is not None:
-                ksc = dus(ksc, new_kv[2][None], li, axis=0)
-                vsc = dus(vsc, new_kv[3][None], li, axis=0)
+            with scope("kv_write"):
+                kp = dus(kp, new_kv[0][None], li, axis=0)
+                vp = dus(vp, new_kv[1][None], li, axis=0)
+                if ksc is not None:
+                    ksc = dus(ksc, new_kv[2][None], li, axis=0)
+                    vsc = dus(vsc, new_kv[3][None], li, axis=0)
         cache = dict(cache)
         cache["k_pages"], cache["v_pages"] = kp, vp
         if ksc is not None:
@@ -390,13 +397,16 @@ def decode(params, cfg, batch, cache):
         h = x
         kc, vc = cache["k"], cache["v"]
         for li in range(cfg.n_layers):
-            lp = jax.tree.map(lambda a, li=li: a[li], params["blocks"])
+            lp = _layer_params(params["blocks"], li)
             h, (kl, vl) = L.block_apply(
                 lp, h, positions, cfg, causal=False, window=wins[li],
                 kv_lens=pos + 1, q_offset=pos, cache=(kc[li], vc[li]),
                 cache_pos=pos)
-            kc = jax.lax.dynamic_update_slice_in_dim(kc, kl[None], li, axis=0)
-            vc = jax.lax.dynamic_update_slice_in_dim(vc, vl[None], li, axis=0)
+            with scope("kv_write"):
+                kc = jax.lax.dynamic_update_slice_in_dim(kc, kl[None], li,
+                                                         axis=0)
+                vc = jax.lax.dynamic_update_slice_in_dim(vc, vl[None], li,
+                                                         axis=0)
         cache = dict(cache)
         cache["k"], cache["v"] = kc, vc
     else:
@@ -414,9 +424,24 @@ def decode(params, cfg, batch, cache):
         cache["k"], cache["v"] = k_new, v_new
 
     cache["pos"] = pos + 1
-    h = L.apply_norm(params["final_norm"], h, cfg)
-    logits = L.unembed(params["embed"], h, cfg)[:, 0]
+    with scope("logits"):
+        h = L.apply_norm(params["final_norm"], h, cfg)
+        logits = L.unembed(params["embed"], h, cfg)[:, 0]
     return logits, cache
+
+
+_PART_OF = {"ln1": "attn_proj", "attn": "attn_proj", "mlp": "mlp",
+            "ln2": "mlp"}
+
+
+def _layer_params(blocks, li: int) -> dict:
+    """Layer ``li``'s weights out of the stacked ``blocks``, each slice in
+    the scope of the part that reads it (XLA may copy a slice out)."""
+    lp = {}
+    for key, stacked in blocks.items():
+        with scope(_PART_OF[key]):
+            lp[key] = jax.tree.map(lambda a: a[li], stacked)
+    return lp
 
 
 def _cross_decode(block_p, h, positions, cfg, ck, cv):

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from repro.core import paging as PG
 from repro.dist import sharding as SH
 from repro.kernels.flash_attention import flash_attention
+from repro.obs.scopes import scope
 
 
 def shard_act(cfg, x, axes):
@@ -155,6 +156,7 @@ def embed_axes(cfg):
     return ax
 
 
+@scope("embed")
 def embed(p, ids, cfg):
     x = jnp.take(p["tok"], ids, axis=0).astype(cdt(cfg))
     if cfg.embed_scale:
@@ -162,6 +164,7 @@ def embed(p, ids, cfg):
     return shard_act(cfg, x, ("batch", None, None))
 
 
+@scope("logits")
 def unembed(p, x, cfg):
     w = (p["tok"].T if cfg.tie_embeddings else p["unembed"]).astype(cdt(cfg))
     logits = jnp.einsum("bsd,dv->bsv", x.astype(cdt(cfg)), w)
@@ -211,6 +214,7 @@ def mlp_axes(cfg, d_ff: Optional[int] = None):
     return ax
 
 
+@scope("mlp")
 def mlp(p, x, cfg):
     act_axes = ("batch",) + (None,) * (x.ndim - 2) + ("act_mlp",)
     xc = x.astype(cdt(cfg))
@@ -303,6 +307,37 @@ def attention(p, x, positions, cfg, *,
     - ``window``: None | int | scalar array — dynamic sliding window, one
       predicated kernel for local AND global layers (DESIGN.md C2).
     """
+    with scope("attn_proj"):
+        q, k, v = _project_qkv(p, x, positions, cfg, kv_x, use_rope)
+    page_table = k_sc = v_sc = new_cache = None
+    if cache is not None:
+        with scope("kv_write"):
+            k, v, page_table, k_sc, v_sc, new_cache = _store_kv(
+                cfg, cache, cache_pos, k, v)
+
+    with scope("attn_core"):
+        out = flash_attention(
+            q, k, v, kv_lens=kv_lens, causal=causal, window=window,
+            q_offset=q_offset, impl=cfg.attn_impl, page_table=page_table,
+            k_scale=k_sc, v_scale=v_sc)
+        out = shard_act(cfg, out, ("batch", "act_heads", None, None))
+    with scope("attn_proj"):
+        # the out-proj input: under training rules act_attn_in rides "model"
+        # (Megatron row-parallel, psum after the dot); under SERVE_RULES it
+        # replicates, gathering the merged heads BEFORE the dot so the
+        # contraction runs whole and logits stay bitwise-identical to
+        # 1-device
+        merged = shard_act(cfg, _merge_heads(out).astype(cdt(cfg)),
+                           ("batch", None, "act_attn_in"))
+        out = merged @ p["wo"].astype(cdt(cfg))
+        if cfg.use_bias:
+            out = out + p["bo"].astype(cdt(cfg))
+        out = shard_act(cfg, out, ("batch", None, None))
+    return out.astype(x.dtype), new_cache
+
+
+def _project_qkv(p, x, positions, cfg, kv_x, use_rope):
+    """Q/K/V projections as (B, H, S, Dh), with qk-norm and rope."""
     hd = cfg.resolved_head_dim
     xc = x.astype(cdt(cfg))
     src = xc if kv_x is None else kv_x.astype(cdt(cfg))
@@ -325,64 +360,50 @@ def attention(p, x, positions, cfg, *,
     if use_rope and kv_x is None:
         q = rope(q, positions, cfg.rope_theta, cfg.partial_rotary_factor)
         k = rope(k, positions, cfg.rope_theta, cfg.partial_rotary_factor)
+    return q, k, v
 
-    new_cache = None
-    page_table = None
+
+def _store_kv(cfg, cache, cache_pos, k, v):
+    """Store the new K/V at ``cache_pos`` into a dense or paged ``cache``.
+    Returns the K/V attention reads (the cache, or the pools), the page
+    table, the scale pools of a quantized cache, and the new cache."""
     k_sc = v_sc = None
-    if cache is not None:
-        if len(cache) in (3, 5):
-            # paged cache (k_pool, v_pool, page_table[, k_scale, v_scale]):
-            # scatter-store the new token into the lane's tail page; attention
-            # gathers K/V blocks through the page table (SVE §2.3.3).  The
-            # 5-tuple is a QUANTIZED cache: the scatter truncates to the
-            # narrow pool dtype (per-slot absmax scale) and the gather widens
-            # in register.  Decode-only (Snew == 1).
-            k_pool, v_pool, page_table = cache[:3]
-            ps = k_pool.shape[2]
-            page_col = jnp.clip(cache_pos // ps, 0, page_table.shape[1] - 1)
-            page_ids = jnp.take_along_axis(page_table, page_col[:, None],
-                                           axis=1)[:, 0]
-            off = cache_pos % ps
-            if len(cache) == 5:
-                k_sc, v_sc = cache[3], cache[4]
-                k_pool, k_sc = PG.scatter_page_q(k_pool, k_sc, page_ids, off,
-                                                 k[:, :, 0, :])
-                v_pool, v_sc = PG.scatter_page_q(v_pool, v_sc, page_ids, off,
-                                                 v[:, :, 0, :])
-                k_sc, v_sc = shard_scale(cfg, k_sc), shard_scale(cfg, v_sc)
-                k_pool = shard_pool(cfg, k_pool)
-                v_pool = shard_pool(cfg, v_pool)
-                k, v = k_pool, v_pool            # narrow: widened in-gather
-                new_cache = (k_pool, v_pool, k_sc, v_sc)
-            else:
-                k_pool = shard_pool(cfg, PG.scatter_page(
-                    k_pool, page_ids, off, k[:, :, 0, :]))
-                v_pool = shard_pool(cfg, PG.scatter_page(
-                    v_pool, page_ids, off, v[:, :, 0, :]))
-                k, v = k_pool.astype(cdt(cfg)), v_pool.astype(cdt(cfg))
-                new_cache = (k_pool, v_pool)
+    if len(cache) in (3, 5):
+        # paged cache (k_pool, v_pool, page_table[, k_scale, v_scale]):
+        # scatter-store the new token into the lane's tail page; attention
+        # gathers K/V blocks through the page table (SVE §2.3.3).  The
+        # 5-tuple is a QUANTIZED cache: the scatter truncates to the
+        # narrow pool dtype (per-slot absmax scale) and the gather widens
+        # in register.  Decode-only (Snew == 1).
+        k_pool, v_pool, page_table = cache[:3]
+        ps = k_pool.shape[2]
+        page_col = jnp.clip(cache_pos // ps, 0, page_table.shape[1] - 1)
+        page_ids = jnp.take_along_axis(page_table, page_col[:, None],
+                                       axis=1)[:, 0]
+        off = cache_pos % ps
+        if len(cache) == 5:
+            k_sc, v_sc = cache[3], cache[4]
+            k_pool, k_sc = PG.scatter_page_q(k_pool, k_sc, page_ids, off,
+                                             k[:, :, 0, :])
+            v_pool, v_sc = PG.scatter_page_q(v_pool, v_sc, page_ids, off,
+                                             v[:, :, 0, :])
+            k_sc, v_sc = shard_scale(cfg, k_sc), shard_scale(cfg, v_sc)
+            k_pool = shard_pool(cfg, k_pool)
+            v_pool = shard_pool(cfg, v_pool)
+            k, v = k_pool, v_pool            # narrow: widened in-gather
+            new_cache = (k_pool, v_pool, k_sc, v_sc)
         else:
-            k_cache, v_cache = cache
-            k_cache, v_cache = update_kv_cache(k_cache, v_cache, k, v, cache_pos)
-            k, v = k_cache.astype(cdt(cfg)), v_cache.astype(cdt(cfg))
-            new_cache = (k_cache, v_cache)
-
-    out = flash_attention(
-        q, k, v, kv_lens=kv_lens, causal=causal, window=window,
-        q_offset=q_offset, impl=cfg.attn_impl, page_table=page_table,
-        k_scale=k_sc, v_scale=v_sc)
-    out = shard_act(cfg, out, ("batch", "act_heads", None, None))
-    # the out-proj input: under training rules act_attn_in rides "model"
-    # (Megatron row-parallel, psum after the dot); under SERVE_RULES it
-    # replicates, gathering the merged heads BEFORE the dot so the
-    # contraction runs whole and logits stay bitwise-identical to 1-device
-    merged = shard_act(cfg, _merge_heads(out).astype(cdt(cfg)),
-                       ("batch", None, "act_attn_in"))
-    out = merged @ p["wo"].astype(cdt(cfg))
-    if cfg.use_bias:
-        out = out + p["bo"].astype(cdt(cfg))
-    out = shard_act(cfg, out, ("batch", None, None))
-    return out.astype(x.dtype), new_cache
+            k_pool = shard_pool(cfg, PG.scatter_page(
+                k_pool, page_ids, off, k[:, :, 0, :]))
+            v_pool = shard_pool(cfg, PG.scatter_page(
+                v_pool, page_ids, off, v[:, :, 0, :]))
+            k, v = k_pool.astype(cdt(cfg)), v_pool.astype(cdt(cfg))
+            new_cache = (k_pool, v_pool)
+        return k, v, page_table, k_sc, v_sc, new_cache
+    k_cache, v_cache = cache
+    k_cache, v_cache = update_kv_cache(k_cache, v_cache, k, v, cache_pos)
+    k, v = k_cache.astype(cdt(cfg)), v_cache.astype(cdt(cfg))
+    return k, v, None, None, None, (k_cache, v_cache)
 
 
 # ---------------------------------------------------------------------------
@@ -410,7 +431,8 @@ def block_apply(p, x, positions, cfg, *, causal=True, window=None,
                 kv_lens=None, q_offset=None, cache=None, cache_pos=None,
                 kv_x=None, use_rope=True):
     x = shard_residual(cfg, x)
-    h = apply_norm(p["ln1"], x, cfg)
+    with scope("attn_proj"):            # the input norm feeds the projections
+        h = apply_norm(p["ln1"], x, cfg)
     attn_out, new_cache = attention(
         p["attn"], h, positions, cfg, kv_x=kv_x, causal=causal, window=window,
         kv_lens=kv_lens, q_offset=q_offset, cache=cache, cache_pos=cache_pos,
@@ -419,7 +441,8 @@ def block_apply(p, x, positions, cfg, *, causal=True, window=None,
         out = x + attn_out + mlp(p["mlp"], h, cfg)
     else:
         h2 = x + attn_out
-        out = h2 + mlp(p["mlp"], apply_norm(p["ln2"], h2, cfg), cfg)
+        with scope("mlp"):
+            out = h2 + mlp(p["mlp"], apply_norm(p["ln2"], h2, cfg), cfg)
     return shard_residual(cfg, out), new_cache
 
 

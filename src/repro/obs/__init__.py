@@ -1,6 +1,6 @@
 """`repro.obs` — zero-sync serve observability.
 
-Three pieces, threaded through the serving stack at host-side seams only:
+Four pieces, threaded through the serving stack at host-side seams only:
 
 * :mod:`repro.obs.metrics` — a typed metrics registry (counters, gauges,
   series, fixed-bucket log2 histograms) that backs the scheduler's ``stats``
@@ -9,7 +9,12 @@ Three pieces, threaded through the serving stack at host-side seams only:
   ``trace_event`` JSON: round anatomy spans on the scheduler track plus one
   lifecycle track per request.
 * :mod:`repro.obs.recorder` — the ``Obs`` facade the engine/scheduler/bench
-  accept (``obs=...``), with a free no-op path when tracing is off.
+  accept (``obs=...``), with a free no-op path when tracing is off; with
+  ``xla_annotations=True`` every serve-loop span is also a
+  ``jax.profiler.TraceAnnotation`` (with or without a tracer).
+* :mod:`repro.obs.scopes` — the fixed names a profile is read by: the
+  ``jax.named_scope`` phases and parts of the serve programs on the device,
+  and the serve loop's span names on the host.
 
 The hard contract (tested in tests/test_obs.py): with tracing ON, served
 tokens stay byte-identical and ``dispatches``/``host_syncs`` do not move —

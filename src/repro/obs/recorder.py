@@ -1,23 +1,27 @@
 """The ``Obs`` facade the serving stack threads through (``obs=...``).
 
-One object bundles the two sinks — a :class:`~repro.obs.metrics.
-MetricsRegistry` (always present; it backs ``scheduler.stats``) and an
-optional :class:`~repro.obs.trace.Tracer` — behind no-op-cheap entry
-points.  Every hook degrades to a single attribute test when tracing is
-off: ``span`` returns the shared :data:`NULL_SPAN`, ``event``/``counter``/
-``request_*`` return immediately.  That is the "tracing OFF costs nothing
-measurable" half of the contract; the other half (tracing ON moves no
-tokens and no ``dispatches``/``host_syncs``) holds because every hook
-records only host-resident values.
+One object bundles three sinks — a :class:`~repro.obs.metrics.
+MetricsRegistry` (always present; it backs ``scheduler.stats``), an
+optional :class:`~repro.obs.trace.Tracer`, and optional profiler
+annotations — behind no-op-cheap entry points.  Every hook degrades to a
+single attribute test when tracing is off: ``span`` returns the shared
+:data:`NULL_SPAN`, ``event``/``counter``/``request_*`` return immediately.
+That is the "tracing OFF costs nothing measurable" half of the contract;
+the other half (tracing ON moves no tokens and no ``dispatches``/
+``host_syncs``) holds because every hook records only host-resident values.
 
-``xla_annotations=True`` additionally wraps ``span(..., xla=True)`` seams
-in ``jax.profiler.TraceAnnotation`` so a concurrently-captured XLA profile
-(``jax.profiler.trace``) interleaves the device timeline with these spans.
+``xla_annotations=True`` makes every serve-loop span (the names of
+:data:`repro.obs.scopes.SPANS`) a ``jax.profiler.TraceAnnotation`` as
+well, so a concurrently captured XLA profile (``jax.profiler.trace``)
+holds the host's rounds on the device's clock.  It is a sink of its own:
+without a tracer the spans annotate and nothing else is recorded.
 """
 
 from __future__ import annotations
 
 from typing import Optional
+
+from jax.profiler import TraceAnnotation
 
 from .metrics import MetricsRegistry
 from .trace import Tracer
@@ -40,15 +44,6 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
-def _trace_annotation(name: str):
-    """A ``jax.profiler.TraceAnnotation`` (None when unavailable)."""
-    try:
-        from jax.profiler import TraceAnnotation
-    except Exception:                                    # pragma: no cover
-        return None
-    return TraceAnnotation(name)
-
-
 class Obs:
     """Observability handle: a metrics registry plus an optional tracer.
 
@@ -58,8 +53,8 @@ class Obs:
         want engine + scheduler + bench in one registry pass it explicitly).
     tracer: a :class:`Tracer` to record the span timeline into, or None
         (the default) for metrics-only operation.
-    xla_annotations: wrap dispatch-seam spans in
-        ``jax.profiler.TraceAnnotation`` so XLA device profiles interleave.
+    xla_annotations: make every span a ``jax.profiler.TraceAnnotation``
+        too, so XLA device profiles interleave (with or without a tracer).
     """
 
     def __init__(self, metrics: Optional[MetricsRegistry] = None,
@@ -74,16 +69,16 @@ class Obs:
         return self.tracer is not None
 
     # ------------------------------------------------------------------
-    # span/event hooks (no-ops without a tracer)
+    # span/event hooks (no-ops without a tracer; spans also annotate the
+    # profiler with ``xla_annotations``)
     # ------------------------------------------------------------------
 
-    def span(self, name: str, xla: bool = False, **args):
-        """Span on the serve-loop track; ``xla=True`` marks a dispatch seam
-        eligible for the TraceAnnotation wrapper."""
+    def span(self, name: str, **args):
+        """Span on the serve-loop track, and a profiler annotation with
+        ``xla_annotations``."""
         if self.tracer is None:
-            return NULL_SPAN
-        ann = (_trace_annotation(name)
-               if xla and self.xla_annotations else None)
+            return TraceAnnotation(name) if self.xla_annotations else NULL_SPAN
+        ann = TraceAnnotation(name) if self.xla_annotations else None
         return self.tracer.span(name, ann=ann, **args)
 
     def event(self, name: str, **args):

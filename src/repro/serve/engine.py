@@ -40,6 +40,7 @@ from repro.models import (gather_lanes, get_model, is_paged, merge_lanes,
                           paged_decode_ok, paged_view, paged_writeback,
                           slot_update, to_paged)
 from repro.obs import Obs
+from repro.obs.scopes import scope
 from repro.sample.processors import ban_pred, mask_logits
 
 
@@ -177,6 +178,7 @@ class ServeEngine:
         run.lower = lower
         return run
 
+    @scope("sample")
     def _sample(self, logits, sstate=None, out_buf=None, n_gen=None):
         """Sample one token per lane through ``repro.sample`` (the single
         sampler entry point).  With no state: bit-exact greedy argmax."""
@@ -235,6 +237,7 @@ class ServeEngine:
                            lane_budget, sstate, n_steps=n_steps,
                            stochastic=stochastic, width=width)
 
+    @scope("decode")
     def _burst(self, params, cache, out_buf, tok, p, n_gen, lane_budget,
                sstate, *, n_steps: int, stochastic: bool,
                width: Optional[int]):
@@ -336,6 +339,7 @@ class ServeEngine:
     # dispatch (the scalar-loop-tail elimination applied to the host loop)
     # ------------------------------------------------------------------
 
+    @scope("kv_write")
     def _seed_pages(self, cache, sub_cache, seed_tab, seed_len, max_len: int):
         """Gather resident shared-prefix pages of the live paged ``cache``
         into a dense prefill ``sub_cache`` (positions [0, seed_len) per row),
@@ -355,6 +359,7 @@ class ServeEngine:
                                        sub_cache[key])
         return sub_cache
 
+    @scope("kv_write")
     def _install_pages(self, cache, sub_cache, rows, cols, dsts, tab_rows,
                        lanes):
         """Scatter freshly prefilled K/V blocks ``(rows, cols)`` of the dense
@@ -504,6 +509,23 @@ class ServeEngine:
         (cache, out_buf, tok, p, n_gen, budget, sstate, steps_run,
         new_caches-of-non-final-partials).
         """
+        (cache, out_buf, tok, p, n_gen, budget, sstate,
+         new_part_caches) = self._fused_prefill(
+            params, cache, out_buf, tok, p, n_gen, budget, sstate, admit,
+            parts, admit_stoch=admit_stoch, part_final=part_final,
+            part_stoch=part_stoch, max_len=max_len)
+        cache, out_buf, tok, p, n_gen, sstate, steps = self._burst(
+            params, cache, out_buf, tok, p, n_gen, budget, sstate,
+            n_steps=n_steps, stochastic=stochastic, width=width)
+        return (cache, out_buf, tok, p, n_gen, budget, sstate, steps,
+                tuple(new_part_caches))
+
+    @scope("prefill")
+    def _fused_prefill(self, params, cache, out_buf, tok, p, n_gen, budget,
+                       sstate, admit, parts, *, admit_stoch, part_final,
+                       part_stoch, max_len):
+        """The fused round's prefill phase: the chunked-prefill chunks, then
+        the admission sub-batch, each spliced into its lanes."""
         new_part_caches = []
         for i, part in enumerate(parts):
             sub_in = part["cache"]
@@ -549,11 +571,8 @@ class ServeEngine:
                 cache, out_buf, tok, p, n_gen, budget, sstate,
                 admit["lanes"], first, sub_cache, sub_state,
                 admit["budgets"], admit)
-        cache, out_buf, tok, p, n_gen, sstate, steps = self._burst(
-            params, cache, out_buf, tok, p, n_gen, budget, sstate,
-            n_steps=n_steps, stochastic=stochastic, width=width)
-        return (cache, out_buf, tok, p, n_gen, budget, sstate, steps,
-                tuple(new_part_caches))
+        return (cache, out_buf, tok, p, n_gen, budget, sstate,
+                new_part_caches)
 
     # ------------------------------------------------------------------
     # one-shot batch API
@@ -610,7 +629,7 @@ class ServeEngine:
         cache = self.make_cache(b, max_len, batch)
         sstate = self.make_state(b, sampling)
 
-        with self.obs.span("prefill", xla=True, b=b, s=s):
+        with self.obs.span("prefill", b=b, s=s):
             logits, cache = self._prefill(self.params,
                                           dict(batch, lens=lens), cache)
         if page_size is not None:
@@ -630,7 +649,7 @@ class ServeEngine:
         budget = jnp.full((b,), max_new, jnp.int32)
         p0 = (first_tok != self.stop_token) & (budget > 1)
         # ---- single dispatch: the whole decode loop runs inside XLA ----
-        with self.obs.span("decode", xla=True, b=b, n_steps=max_new):
+        with self.obs.span("decode", b=b, n_steps=max_new):
             cache, out, tok, _, n_gen, _, _ = self._decode_chunk(
                 self.params, cache, out, first_tok, p0,
                 jnp.ones((b,), jnp.int32), budget, sstate, n_steps=max_new,

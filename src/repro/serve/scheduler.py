@@ -861,7 +861,7 @@ class ContinuousBatchingScheduler:
                 gen_before = int(self._block_on(self.n_gen.sum(),
                                                 "gen_before"))
                 self.stats["dispatches"] += 1
-                with self.obs.span("burst", xla=True, chunk=self.chunk):
+                with self.obs.span("burst", chunk=self.chunk):
                     (self.cache, self.out_buf, self.tok, self.p,
                      self.n_gen, self.sstate, steps) = eng._decode_chunk(
                         eng.params, self.cache, self.out_buf, self.tok,
@@ -917,10 +917,8 @@ class ContinuousBatchingScheduler:
             self.stats["dispatches"] += 1
             if plan is None and not part_steps:
                 width = self._burst_width()
-                with obs.span("dispatch", xla=True, kind="decode",
+                with obs.span("dispatch", kind="decode", chunk=self.chunk,
                               width=width or self.capacity):
-                    obs.event("burst", chunk=self.chunk,
-                              width=width or self.capacity)
                     (self.cache, self.out_buf, self.tok, self.p, self.n_gen,
                      self.sstate, steps_h) = eng._decode_chunk_serve(
                         eng.params, self.cache, self.out_buf, self.tok,
@@ -942,10 +940,8 @@ class ContinuousBatchingScheduler:
                 # ordering as the unfused loop)
                 stoch = bool(self._lane_stoch.any())
                 width = self._burst_width()
-                with obs.span("dispatch", xla=True, kind="fused",
+                with obs.span("dispatch", kind="fused", chunk=self.chunk,
                               width=width or self.capacity):
-                    obs.event("burst", chunk=self.chunk,
-                              width=width or self.capacity)
                     (self.cache, self.out_buf, self.tok, self.p, self.n_gen,
                      self.budget, self.sstate, steps_h,
                      parts_out) = eng._fused_step(
@@ -1668,7 +1664,7 @@ class ContinuousBatchingScheduler:
         if self.page_size is not None:
             sub_cache = self._seed_shared_prefix(sub_cache, plan.plans, n_pad)
         self.stats["dispatches"] += 1
-        with self.obs.span("admit", xla=True, n=n):
+        with self.obs.span("admit", n=n):
             logits, sub_cache = eng._prefill(eng.params, batch, sub_cache)
         # per-request sampler rows: built from each request's OWN spec/seed
         # (dummy pad rows are greedy with a zero key), first token sampled
@@ -1870,8 +1866,8 @@ class ContinuousBatchingScheduler:
                     self.cache, s.part.sub_cache, s.seed[0], s.seed[1],
                     self.max_len)
             self.stats["dispatches"] += 1
-            with self.obs.span("prefill_chunk", xla=True,
-                               rid=s.part.req.rid, final=s.final):
+            with self.obs.span("prefill_chunk", rid=s.part.req.rid,
+                               final=s.final):
                 logits, s.part.sub_cache = self.engine._prefill(
                     self.engine.params, batch, s.part.sub_cache)
             if s.final:

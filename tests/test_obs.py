@@ -23,8 +23,9 @@ import numpy as np
 import pytest
 
 from repro.models import ModelConfig, get_model
-from repro.obs import (LogHistogram, MetricsRegistry, Obs, StatsView, Tracer,
-                       validate_trace)
+from repro.obs import (NULL_SPAN, LogHistogram, MetricsRegistry, Obs,
+                       StatsView, Tracer, validate_trace)
+from repro.obs.scopes import SPANS
 from repro.obs.trace import PID_REQUESTS, PID_SERVE
 from repro.serve import ContinuousBatchingScheduler, SamplingParams, ServeEngine
 
@@ -134,17 +135,68 @@ def test_tracing_identity_all_families(family):
 
 
 def test_off_recorder_is_noop(engine):
-    """Without a tracer every hook is a no-op (shared NULL_SPAN, immediate
-    returns) — nothing accumulates anywhere but the metrics registry."""
+    """Without a tracer or annotations every hook is a no-op (shared
+    NULL_SPAN, immediate returns) — nothing accumulates anywhere but the
+    metrics registry."""
     obs = Obs()
     assert not obs.tracing
-    span = obs.span("round", xla=True)
-    assert span is obs.span("anything")          # the shared singleton
+    for name in SPANS:
+        assert obs.span(name, what="x") is NULL_SPAN   # the shared singleton
     obs.event("x")
+    obs.counter("occupancy", 0.5)
     obs.request_begin(0)
     obs.request_event(0, "y")
     obs.request_end(0)
     assert obs.export("/nonexistent/never-written.json") == 0
+
+
+def test_annotations_without_tracer_record_nothing():
+    """``xla_annotations`` alone: every span is a profiler annotation of its
+    own name, and nothing else is recorded."""
+    obs = Obs(xla_annotations=True)
+    assert not obs.tracing
+    span = obs.span("round", round=3)
+    assert isinstance(span, jax.profiler.TraceAnnotation)
+    with span:
+        with obs.span("sync", what="harvest"):
+            pass
+    obs.event("x")
+    obs.request_begin(0)
+    obs.request_end(0)
+    assert obs.tracer is None
+    assert obs.export("/nonexistent/never-written.json") == 0
+    # with a tracer too, the span records and annotates
+    both = Obs(tracer=Tracer(), xla_annotations=True)
+    with both.span("round", round=0):
+        pass
+    assert [e["ph"] for e in both.tracer.events] == ["B", "E"]
+
+
+def test_annotated_serve_spans_reach_the_profiler(engine, tmp_path):
+    """A profile of a run with annotations on holds the serve loop's spans
+    by name, and the run serves byte-identical tokens at equal dispatch and
+    host-sync counts."""
+    from jax.profiler import ProfileData
+
+    trace = _trace(np.random.RandomState(2), 6)
+    r_off, s_off = _serve(engine, trace)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        r_on, s_on = _serve(engine, trace, obs=Obs(xla_annotations=True))
+    finally:
+        jax.profiler.stop_trace()
+    for rid in r_off:
+        assert r_off[rid]["tokens"].tobytes() == r_on[rid]["tokens"].tobytes()
+    assert s_on == s_off
+    (pb,) = tmp_path.glob("**/*.xplane.pb")
+    names = {e.name for plane in ProfileData.from_file(str(pb)).planes
+             for line in plane.lines for e in line.events}
+    assert {"round", "plan", "dispatch", "harvest", "sync"} <= names
+    # every span name a traced serve run records is one of SPANS
+    obs = Obs(tracer=Tracer())
+    _serve(engine, trace, obs=obs)
+    assert {e["name"] for e in obs.tracer.events
+            if e["ph"] == "B" and e["pid"] == PID_SERVE} <= set(SPANS)
 
 
 # ----------------------------------------------------------------------
