@@ -28,6 +28,28 @@ def _pick_blocks(sq: int, skv: int, d: int, dtype) -> tuple[int, int]:
     return bq, bk
 
 
+def _pick_pages_per_block(ps: int, n_pages: int, bq: int, hkv: int,
+                          group: int, d: int) -> int:
+    """Pages per kv block of the paged XLA walk, from shapes alone.
+
+    One lane's block works on ``rows = hkv * group * bq`` query rows against
+    ``bk = npb * ps`` keys of every KV head; under _pick_blocks' budget rule
+    that is ~ f32: s(rows,bk) + q(rows,d) + k/v(hkv*bk,d)*2 + acc(rows,d) +
+    m/l(rows,128)*2.  Policy: bk up to 512 tokens, halved (not below 128)
+    until the block fits; the whole table when it is smaller; then the
+    pages are spread evenly over the blocks, so the walk pads the table by
+    fewer than one page a block.
+    """
+    rows = hkv * group * bq
+    budget = vla.VMEM_BYTES // 2
+    npb = max(1, min(n_pages, 512 // ps))
+    while (rows * npb * ps * 4 + (rows + 2 * hkv * npb * ps) * d * 4
+           + rows * (d + 256) * 4 > budget and npb * ps > 128 and npb > 1):
+        npb //= 2
+    n_blocks = -(-n_pages // npb)
+    return -(-n_pages // n_blocks)
+
+
 def flash_attention(
     q, k, v,
     *, kv_lens=None, causal: bool = False, window: int | None = None,
@@ -142,9 +164,11 @@ def _flash_paged(q, k_pool, v_pool, page_table, *, kv_lens, causal, window,
                       jnp.int32).reshape((1,))
     scale_f = float(d ** -0.5) if scale is None else float(scale)
     if impl == "xla":
+        npb = _pick_pages_per_block(ps, n_pages, bq, k_pool.shape[1],
+                                    hq // k_pool.shape[1], d)
         out = flash_attention_xla_paged(
             q, k_pool, v_pool, page_table, kv_lens, q_offset, win[0],
-            causal=causal, scale=scale_f, bq=bq,
+            causal=causal, scale=scale_f, bq=bq, npb=npb,
             k_scale=k_scale, v_scale=v_scale)
     else:
         out = flash_attention_pallas_paged(

@@ -190,68 +190,81 @@ def flash_attention_xla(q, k, v, kv_lens, q_offset, window, *, causal,
 
 
 # ---------------------------------------------------------------------------
-# Paged forward: K/V blocks ARE pages, fetched through the page table
+# Paged forward: a K/V block is npb pages, fetched through the page table
 # ---------------------------------------------------------------------------
 
 def flash_attention_xla_paged(q, k_pool, v_pool, page_table, kv_lens,
-                              q_offset, window, *, causal, scale, bq,
+                              q_offset, window, *, causal, scale, bq, npb,
                               k_scale=None, v_scale=None):
     """Flash forward over a PAGED KV cache (SVE §2.3.3 gather-load).
 
     k_pool / v_pool: ``(P, Hkv, page_size, D)`` page pools; ``page_table``:
-    ``(B, n_pages) int32``.  The kv-block scan walks LOGICAL pages and fetches
-    each lane's physical page with a ``jnp.take`` on the pool — the index
+    ``(B, n_pages) int32``.  The kv-block scan walks LOGICAL pages ``npb`` at
+    a time (ops._pick_pages_per_block chooses it from shapes) and fetches
+    each lane's physical pages with one ``jnp.take`` on the pool — the index
     vector, not the layout, addresses memory, so the same kernel serves any
     physical placement (allocation order, prefix-shared pages, reuse).  The
-    online-softmax math is identical to the dense path with ``bk ==
+    online-softmax math is identical to the dense path with ``bk == npb *
     page_size``; logical positions come from the page index, so masks are
-    unchanged.  Serving/decode only — no VJP.  ``page_table`` arrives with
-    out-of-strip (possibly stale) entries already clamped to page 0 under the
-    page-granular whilelt — ops._flash_paged governs the walk once for every
-    impl.
+    unchanged.  The table is padded to a multiple of ``npb`` with page 0:
+    those columns lie past every ``kv_lens`` and are masked.  Serving/decode
+    only — no VJP.  ``page_table`` arrives with out-of-strip (possibly
+    stale) entries already clamped to page 0 under the page-granular
+    whilelt — ops._flash_paged governs the walk once for every impl.
 
     ``k_scale`` / ``v_scale``: ``(P, Hkv, page_size)`` per-slot scale pools
-    of a QUANTIZED cache — the same ``jnp.take`` that fetches a page fetches
-    its scales and widens the narrow elements in register (SVE §2.3.3
-    extending gather-load): ``kb = q8 * scale``.
+    of a QUANTIZED cache — the same ``jnp.take`` that fetches a block of
+    pages fetches their scales and widens the narrow elements in register
+    (SVE §2.3.3 extending gather-load): ``kb = q8 * scale``.
     """
     b, h, sq, d = q.shape
     hkv, ps = k_pool.shape[1], k_pool.shape[2]
-    n_pg = page_table.shape[1]
     g = h // hkv
+    bk = npb * ps
     f32 = jnp.float32
     nq = sq // bq
     qs = _split_q(q.astype(f32), bq).reshape(nq, b, hkv, g, bq, d)
-    table = page_table
+    n_pg = page_table.shape[1]
+    nk = -(-n_pg // npb)
+    table = jnp.pad(page_table, ((0, 0), (0, nk * npb - n_pg)))
+
+    def fetch(pool, sc, pids):
+        # (B, npb, Hkv, ps, D): kept page-major; the einsums contract over
+        # (p, k) in place rather than transposing the block each step.
+        # Every id is a pool page (stale ones clamped by ops), so "clip"
+        # spares the block the NaN-fill select of the default mode
+        blk = jnp.take(pool, pids, axis=0, mode="clip").astype(f32)
+        if sc is not None:
+            blk = blk * jnp.take(sc, pids, axis=0, mode="clip")[..., None]
+        return blk
 
     def q_block(_, xs):
         qb, iq = xs
 
         def kv_block(carry, ik):
             m, l, acc = carry
-            pids = table[:, ik]
-            kb = jnp.take(k_pool, pids, axis=0).astype(f32)   # (B,Hkv,ps,D)
-            vb = jnp.take(v_pool, pids, axis=0).astype(f32)
-            if k_scale is not None:
-                kb = kb * jnp.take(k_scale, pids, axis=0)[..., None]
-            if v_scale is not None:
-                vb = vb * jnp.take(v_scale, pids, axis=0)[..., None]
-            s = jnp.einsum("bhgqd,bhkd->bhgqk", qb, kb) * scale
-            pred = _block_pred(iq, ik, bq, ps, kv_lens, q_offset, window,
+            pids = jax.lax.dynamic_slice_in_dim(table, ik * npb, npb, axis=1)
+            kb = fetch(k_pool, k_scale, pids)
+            vb = fetch(v_pool, v_scale, pids)
+            s = jnp.einsum("bhgqd,bphkd->bhgqpk", qb, kb)
+            s = s.reshape(b, hkv, g, bq, bk) * scale
+            pred = _block_pred(iq, ik, bq, bk, kv_lens, q_offset, window,
                                causal)[:, None, None]
             s = jnp.where(pred, s, NEG_INF)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1))
             alpha = jnp.exp(m - m_new)
             p = jnp.where(pred, jnp.exp(s - m_new[..., None]), 0.0)
             l = alpha * l + jnp.sum(p, axis=-1)
-            acc = acc * alpha[..., None] + jnp.einsum("bhgqk,bhkd->bhgqd", p, vb)
+            pv = jnp.einsum("bhgqpk,bphkd->bhgqd",
+                            p.reshape(b, hkv, g, bq, npb, ps), vb)
+            acc = acc * alpha[..., None] + pv
             return (m_new, l, acc), None
 
         init = (jnp.full((b, hkv, g, bq), NEG_INF, f32),
                 jnp.zeros((b, hkv, g, bq), f32),
                 jnp.zeros((b, hkv, g, bq, d), f32))
         (m, l, acc), _ = jax.lax.scan(kv_block, init,
-                                      jnp.arange(n_pg, dtype=jnp.int32))
+                                      jnp.arange(nk, dtype=jnp.int32))
         out_b = jnp.where(l[..., None] > 0.0,
                           acc / jnp.maximum(l[..., None], 1e-30), 0.0)
         return None, out_b

@@ -140,35 +140,105 @@ def test_page_whilelt():
 # paged flash attention reads through the page table
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("impl", ["naive", "xla", "kernel"])
-def test_paged_flash_matches_dense(impl):
-    rng = np.random.RandomState(0)
-    B, Hq, Hkv, D, ps, npg, P = 2, 4, 2, 16, 8, 3, 9
-    S = npg * ps
-    kd = rng.randn(B, Hkv, S, D).astype(np.float32)
-    vd = rng.randn(B, Hkv, S, D).astype(np.float32)
-    q = jnp.asarray(rng.randn(B, Hq, 1, D).astype(np.float32))
-    perm = rng.permutation(P)[:B * npg]
-    table = np.zeros((B, npg), np.int32)
-    pool_k = np.zeros((P, Hkv, ps, D), np.float32)
-    pool_v = np.zeros((P, Hkv, ps, D), np.float32)
-    it = iter(perm)
-    for b in range(B):
-        for j in range(npg):
-            pid = int(next(it))
-            table[b, j] = pid
-            pool_k[pid] = kd[b, :, j * ps:(j + 1) * ps, :]
-            pool_v[pid] = vd[b, :, j * ps:(j + 1) * ps, :]
-    kv_lens = jnp.asarray([11, S], jnp.int32)
-    q_off = kv_lens - 1
+# Lanes of 1 (one live slot), 21 (ragged, mid-page) and 48 (the whole row)
+# tokens over a 6-page table of 8-token pages, GQA group 2.  The XLA walk is
+# forced to 1, 2, 4 (does not divide 6) and 6 (the whole table) pages a
+# block; the default cases run the shape-chosen block.
+PF_B, PF_HQ, PF_HKV, PF_D, PF_PS, PF_NPG, PF_P = 3, 4, 2, 16, 8, 6, 20
+PF_LENS = (1, 21, 48)
+
+
+def _walks(default_ids, *extra):
+    """(impl, npb, sq, *extra) cases: ``default_ids`` name the decode cases
+    of every impl at the chosen block; the rest force the XLA walk's
+    block."""
+    impls = ("naive", "xla", "kernel")
+    cases = [pytest.param(impl, None, sq, *extra,
+                          id=tid + ("-sq3" if sq > 1 else ""))
+             for sq in (1, 3) for impl, tid in zip(impls, default_ids)]
+    cases += [pytest.param("xla", npb, sq, *extra,
+                           id=f"{default_ids[1]}-npb{npb}"
+                           + ("-sq3" if sq > 1 else ""))
+              for npb in (1, 2, 4, 6) for sq in (1, 3)]
+    return cases
+
+
+def _paged_problem(seed, sq):
+    """Dense K/V, q, and the same K/V scattered into pools under a permuted
+    page table whose entries past each lane's kv_len are STALE: they name
+    live pages of other lanes, which the clamp + predicate must ignore."""
+    rng = np.random.RandomState(seed)
+    S = PF_NPG * PF_PS
+    kd = rng.randn(PF_B, PF_HKV, S, PF_D).astype(np.float32)
+    vd = rng.randn(PF_B, PF_HKV, S, PF_D).astype(np.float32)
+    q = rng.randn(PF_B, PF_HQ, sq, PF_D).astype(np.float32)
+    table = rng.permutation(PF_P)[:PF_B * PF_NPG].reshape(PF_B, PF_NPG)
+    table = table.astype(np.int32)
+    kv_lens = np.asarray(PF_LENS, np.int32)
+    live = np.asarray(PG.page_whilelt(jnp.asarray(kv_lens), PF_NPG, PF_PS))
+    live_ids = table[live]
+    stale = table.copy()
+    stale[~live] = rng.choice(live_ids, size=int((~live).sum()))
+    q_off = np.maximum(kv_lens - sq, 0).astype(np.int32)
+    return kd, vd, q, table, stale, jnp.asarray(kv_lens), jnp.asarray(q_off)
+
+
+def _force_npb(monkeypatch, npb):
+    if npb is not None:
+        from repro.kernels.flash_attention import ops
+        monkeypatch.setattr(ops, "_pick_pages_per_block",
+                            lambda *shape: npb)
+
+
+@pytest.mark.parametrize("impl,npb,sq", _walks(("naive", "xla", "kernel")))
+def test_paged_flash_matches_dense(impl, npb, sq, monkeypatch):
+    _force_npb(monkeypatch, npb)
+    kd, vd, q, table, stale, kv_lens, q_off = _paged_problem(0, sq)
+    pool_k = np.zeros((PF_P, PF_HKV, PF_PS, PF_D), np.float32)
+    pool_v = np.zeros((PF_P, PF_HKV, PF_PS, PF_D), np.float32)
+    for b in range(PF_B):
+        for j in range(PF_NPG):
+            sl = slice(j * PF_PS, (j + 1) * PF_PS)
+            pool_k[table[b, j]] = kd[b, :, sl, :]
+            pool_v[table[b, j]] = vd[b, :, sl, :]
     ref = flash_attention(jnp.asarray(q), jnp.asarray(kd), jnp.asarray(vd),
                           kv_lens=kv_lens, q_offset=q_off, causal=True,
                           impl="xla")
-    out = flash_attention(q, jnp.asarray(pool_k), jnp.asarray(pool_v),
-                          page_table=jnp.asarray(table), kv_lens=kv_lens,
-                          q_offset=q_off, causal=True, impl=impl)
+    out = flash_attention(jnp.asarray(q), jnp.asarray(pool_k),
+                          jnp.asarray(pool_v), page_table=jnp.asarray(stale),
+                          kv_lens=kv_lens, q_offset=q_off, causal=True,
+                          impl=impl)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
+
+
+def test_pick_pages_per_block_follows_shapes():
+    """Pages per block of the paged XLA walk depend on shapes alone: the
+    whole table for tiny shapes; for the stablelm_3b decode (32 KV heads of
+    80) and the StableLM-2-12B decode (8 KV heads of 160, group 4) over
+    16-token pages, blocks of 256-512 tokens inside _pick_blocks' working-
+    set budget, spread so the walk pads the table by under a page a block."""
+    from repro.core import vla
+    from repro.kernels.flash_attention.ops import _pick_pages_per_block
+
+    def working_set(npb, ps, bq, hkv, group, d):
+        rows, bk = hkv * group * bq, npb * ps
+        return (rows * bk * 4 + (rows + 2 * hkv * bk) * d * 4
+                + rows * (d + 256) * 4)
+
+    assert _pick_pages_per_block(8, 3, 8, 2, 2, 16) == 3
+    assert _pick_pages_per_block(8, PF_NPG, 8, PF_HKV, 2, PF_D) == PF_NPG
+    assert _pick_pages_per_block(16, 1, 8, 32, 1, 80) == 1
+    for shape in [(16, 80, 8, 32, 1, 80), (16, 248, 8, 8, 4, 160)]:
+        ps, n_pages = shape[:2]
+        npb = _pick_pages_per_block(*shape)
+        assert npb == _pick_pages_per_block(*shape)
+        assert 256 <= npb * ps <= 512
+        assert working_set(npb, ps, *shape[2:]) <= vla.VMEM_BYTES // 2
+        n_blocks = -(-n_pages // npb)
+        assert n_blocks * npb - n_pages < n_blocks
+    assert _pick_pages_per_block(16, 80, 8, 32, 1, 80) == 16      # 5 blocks
+    assert _pick_pages_per_block(16, 248, 8, 8, 4, 160) == 31     # 8 blocks
 
 
 # ---------------------------------------------------------------------------
@@ -648,38 +718,43 @@ def test_scatter_page_q_exact_single_token():
     np.testing.assert_array_equal(after_s, before_s)
 
 
-@pytest.mark.parametrize("impl", ["naive", "xla", "kernel"])
-def test_paged_flash_quantized_close_to_dense(impl):
-    """Paged flash attention over int8 pools + scale pools stays within
+@pytest.mark.parametrize(
+    "impl,npb,sq,page_dtype",
+    _walks(("naive", "xla", "kernel"), "int8")
+    + _walks(("naive-fp8", "xla-fp8", "kernel-fp8"), "fp8"))
+def test_paged_flash_quantized_close_to_dense(impl, npb, sq, page_dtype,
+                                              monkeypatch):
+    """Paged flash attention over int8 / fp8 pools + scale pools stays within
     quantization tolerance of dense f32 flash — every impl widens the same
-    narrow bytes through the same page walk."""
-    rng = np.random.RandomState(3)
-    B, Hq, Hkv, D, ps, npg, P = 2, 4, 2, 16, 8, 3, 9
-    S = npg * ps
-    kd = rng.randn(B, Hkv, S, D).astype(np.float32)
-    vd = rng.randn(B, Hkv, S, D).astype(np.float32)
-    q = jnp.asarray(rng.randn(B, Hq, 1, D).astype(np.float32))
-    table = np.arange(B * npg, dtype=np.int32).reshape(B, npg)
-    pool_k = jnp.zeros((P, Hkv, ps, D), jnp.int8)
-    pool_v = jnp.zeros((P, Hkv, ps, D), jnp.int8)
-    sc_k = jnp.zeros((P, Hkv, ps), jnp.float32)
-    sc_v = jnp.zeros((P, Hkv, ps), jnp.float32)
-    ids = jnp.arange(B * npg, dtype=jnp.int32)
+    narrow bytes through the same page walk — and matches dense flash over
+    the widened pages exactly as the f32 walk does."""
+    dt = _quant_dtype_or_skip(page_dtype)
+    _force_npb(monkeypatch, npb)
+    kd, vd, q, table, stale, kv_lens, q_off = _paged_problem(3, sq)
+    ids = jnp.asarray(table.reshape(-1))
     blk = lambda a: jnp.asarray(np.stack(
-        [a[b, :, j * ps:(j + 1) * ps, :] for b in range(B)
-         for j in range(npg)]))
-    pool_k, sc_k = PG.scatter_block_q(pool_k, sc_k, ids, blk(kd))
-    pool_v, sc_v = PG.scatter_block_q(pool_v, sc_v, ids, blk(vd))
-    kv_lens = jnp.asarray([11, S], jnp.int32)
-    q_off = kv_lens - 1
-    ref = flash_attention(q, jnp.asarray(kd), jnp.asarray(vd),
+        [a[b, :, j * PF_PS:(j + 1) * PF_PS, :] for b in range(PF_B)
+         for j in range(PF_NPG)]))
+    pool = jnp.zeros((PF_P, PF_HKV, PF_PS, PF_D), dt)
+    sc = jnp.zeros((PF_P, PF_HKV, PF_PS), jnp.float32)
+    pool_k, sc_k = PG.scatter_block_q(pool, sc, ids, blk(kd))
+    pool_v, sc_v = PG.scatter_block_q(pool, sc, ids, blk(vd))
+    out = flash_attention(jnp.asarray(q), pool_k, pool_v,
+                          page_table=jnp.asarray(stale), kv_lens=kv_lens,
+                          q_offset=q_off, causal=True, impl=impl,
+                          k_scale=sc_k, v_scale=sc_v)
+    ref = flash_attention(jnp.asarray(q), jnp.asarray(kd), jnp.asarray(vd),
                           kv_lens=kv_lens, q_offset=q_off, causal=True,
                           impl="xla")
-    out = flash_attention(q, pool_k, pool_v, page_table=jnp.asarray(table),
-                          kv_lens=kv_lens, q_offset=q_off, causal=True,
-                          impl=impl, k_scale=sc_k, v_scale=sc_v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=0.05, atol=0.05)
+    t = jnp.asarray(table)
+    widened = flash_attention(
+        jnp.asarray(q), PG.gather_pages(pool_k, t, scale=sc_k),
+        PG.gather_pages(pool_v, t, scale=sc_v), kv_lens=kv_lens,
+        q_offset=q_off, causal=True, impl="xla")
+    np.testing.assert_allclose(np.asarray(out), np.asarray(widened),
+                               rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize("family", ["dense", "moe", "hybrid", "encdec", "vlm"])
